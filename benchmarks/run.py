@@ -73,6 +73,8 @@ def main() -> None:
     if args.full and args.smoke:
         p.error("--full and --smoke are mutually exclusive")
     todo = args.only.split(",") if args.only else ALL
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,shards,derived")
     failures = []

@@ -561,7 +561,7 @@ def profile_rows(quick: bool = True, bench: dict = None) -> list[dict]:
                     f"_predicted={pred.get('roofline_ms', 0.0):.4f}ms"
                     f"_bound={pred.get('bottleneck', '-')}"
                     f"_ai={fused.get('ai', 0.0):.2f}"
-                    f"_pct_peak={fused.get('pct_peak', 0.0):.3f}"
+                    f"_pct_peak={fused.get('pct_peak')}"
                     f"_calls={fused.get('calls', 0)}"},
         {"name": "table5/profile/mem_ledger", "us_per_call": 0.0,
          "shards": 1,

@@ -53,7 +53,9 @@ operation         what it computes (paper §3.3 / §4)
 Backends: ``xla`` | ``pallas`` | ``auto`` (Pallas on TPU, XLA elsewhere).
 On non-TPU hosts an explicit ``backend="pallas"`` runs the kernels in
 interpret mode (bit-close to XLA, atol ≲1e-5) so the kernel path is
-testable anywhere.
+testable anywhere. On a TPU the kernels always compile: there is no
+interpret mode and no fallback to XLA, so a kernel the chip's compiler
+refuses fails the call.
 
 Hash families: ``dense`` (plain GEMM SimHash, paper-faithful) | ``srht``
 (subsampled randomized Hadamard transform, the paper's "Approximating
@@ -179,7 +181,6 @@ def _sharded_update_fn(mesh, axis, tau, backend, block_l, interpret, donate):
     foreign rows get their mask zeroed and their slot clamped to 0, so both
     the XLA scatter-add and the Pallas ``sdim_update`` kernel write
     ``store[0] + 0`` for them, a no-op that composes with real slot-0 runs."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def fn(store, shard_ids, locals_, events, mask, R):
@@ -192,12 +193,12 @@ def _sharded_update_fn(mesh, axis, tau, backend, block_l, interpret, donate):
                 interpret=interpret)
             return new[None]
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis, None, None, None, None), P(None), P(None),
                       P(None, None, None), P(None, None), P(None, None)),
             out_specs=P(axis, None, None, None, None),
-            check_rep=False)(store, shard_ids, locals_, events, mask, R)
+            check_vma=False)(store, shard_ids, locals_, events, mask, R)
 
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
@@ -207,7 +208,6 @@ def _sharded_serve_fn(mesh, axis, tau, backend, block_l, interpret):
     """Batch-parallel fused serve: the (B, …) request batch is sharded over
     ``axis`` (callers pad B to a multiple of the axis size); each shard runs
     the whole encode+query pipeline on its B/S users independently."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def fn(q, seq, mask, R):
@@ -215,11 +215,11 @@ def _sharded_serve_fn(mesh, axis, tau, backend, block_l, interpret):
             return _serve(q, seq, mask, r, tau=tau, backend=backend,
                           block_l=block_l, interpret=interpret)
 
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(axis, None, None), P(axis, None, None),
                       P(axis, None), P(None, None)),
-            out_specs=P(axis, None), check_rep=False)(q, seq, mask, R)
+            out_specs=P(axis, None), check_vma=False)(q, seq, mask, R)
 
     return jax.jit(fn)
 
@@ -251,7 +251,6 @@ def _sharded_fused_serve_fn(mesh, axis, tau, backend, block_c, interpret,
     whole batch but owns only its rows — foreign users get their slot
     clamped to 0 and ``present`` zeroed (the kernel's output mask), so the
     psum reassembles exactly one real interest vector per user."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     rep3 = P(None, None, None)
@@ -269,23 +268,23 @@ def _sharded_fused_serve_fn(mesh, axis, tau, backend, block_c, interpret,
             def body(block, scb, sh, lo, pr, q, r):
                 return run_shard(block, scb[0], sh, lo, pr, q, r)
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(axis, None, None, None, None),
                           P(axis, None, None, None),
                           P(None), P(None), P(None), rep3, P(None, None)),
-                out_specs=rep3, check_rep=False)(
+                out_specs=rep3, check_vma=False)(
                 store, scales, shard_ids, locals_, present, q, R)
     else:
         def fn(store, shard_ids, locals_, present, q, R):
             def body(block, sh, lo, pr, q, r):
                 return run_shard(block, None, sh, lo, pr, q, r)
 
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(axis, None, None, None, None),
                           P(None), P(None), P(None), rep3, P(None, None)),
-                out_specs=rep3, check_rep=False)(
+                out_specs=rep3, check_vma=False)(
                 store, shard_ids, locals_, present, q, R)
 
     return jax.jit(fn)
@@ -326,9 +325,14 @@ class SDIMEngine:
 
     @property
     def interpret(self) -> bool:
-        if self.cfg.interpret is not None:
-            return self.cfg.interpret
-        return jax.default_backend() != "tpu"
+        """Interpret mode is for hosts without a TPU. On a TPU the kernels
+        always compile: asking for interpret mode there is an error, so a
+        chip run can never be a silent interpreter run."""
+        on_tpu = jax.default_backend() == "tpu"
+        if self.cfg.interpret and on_tpu:
+            raise ValueError("interpret=True on a TPU: the Pallas kernels "
+                             "must compile for the chip")
+        return not on_tpu if self.cfg.interpret is None else self.cfg.interpret
 
     def _R(self, R: Optional[jax.Array]) -> jax.Array:
         return self.R if R is None else R

@@ -42,8 +42,12 @@ def make_hashes(key: jax.Array, m: int, d: int) -> jax.Array:
 
 
 def hash_codes(x: jax.Array, R: jax.Array) -> jax.Array:
-    """x: (..., d) -> bits (..., m) in {0,1} (int32); bit = [r·x >= 0]."""
-    proj = jnp.einsum("...d,md->...m", x.astype(jnp.float32), R)
+    """x: (..., d) -> bits (..., m) in {0,1} (int32); bit = [r·x >= 0].
+    The projection runs at full fp32 precision: on TPU the default is one
+    bf16 pass, whose rounding would flip the sign of near-zero projections
+    and bucket behaviors differently from the kernels."""
+    proj = jnp.einsum("...d,md->...m", x.astype(jnp.float32), R,
+                      precision=jax.lax.Precision.HIGHEST)
     return (proj >= 0).astype(jnp.int32)
 
 

@@ -1,30 +1,11 @@
-"""Version compatibility shims for the distributed layer.
-
-``jax.sharding.AxisType`` (explicit/auto axis typing) only exists on newer
-JAX. Everything in this repo uses Auto semantics — which is also the default
-when ``axis_types`` is omitted — so on older JAX we simply drop the kwarg
-instead of failing. Feature-detect, never version-compare.
-"""
+"""Mesh construction for the distributed layer."""
 from __future__ import annotations
 
 import jax
 
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-
-def axis_size(name) -> int:
-    """``jax.lax.axis_size`` where available, else psum(1) (same value,
-    computed collectively — works on every JAX that has shard_map)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
 
 def make_auto_mesh(shape: tuple, axes: tuple):
-    """``jax.make_mesh`` with all axes marked Auto when the JAX version
-    supports axis types, plain mesh (same semantics) otherwise."""
-    if HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto: XLA propagates the shardings,
+    which is the semantics every mesh in this repo relies on."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -22,7 +22,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.nn.layers import ACTIVATIONS
 
@@ -60,9 +59,9 @@ def manual_tp_gated_ffn(
     wi_spec = P(dp if dp else None, model)
     wo_spec = P(model, dp if dp else None)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, wi_spec, wi_spec, wo_spec),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, params["wi_gate"]["w"], params["wi_up"]["w"], params["wo"]["w"])

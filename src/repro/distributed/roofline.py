@@ -11,7 +11,9 @@ the *per-partition* module (verified empirically in tests/test_roofline.py);
 collective bytes are parsed from the post-SPMD HLO text (per-partition
 shapes) — XLA does not expose them in cost_analysis.
 
-Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+The peaks are per device kind (``PEAKS``, keyed by ``jax.Device.device_kind``).
+A record without peaks carries the compiled costs only; its time terms are
+an error, so no device is ever timed against another device's peaks.
 """
 from __future__ import annotations
 
@@ -19,9 +21,18 @@ import dataclasses
 import re
 from typing import Optional
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float           # bf16 FLOP/s per chip
+    hbm_bw: float          # HBM bytes/s per chip
+    ici_bw: float          # interconnect bytes/s per link
+
+
+V5E = "TPU v5 lite"        # what jax reports as ``device_kind`` on a v5e chip
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links of 50 GB/s).
+PEAKS = {V5E: Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9)}
 
 COLLECTIVE_OPS = (
     "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -84,18 +95,24 @@ class RooflineRecord:
     collective_breakdown: dict
     peak_memory_per_chip: float     # from memory_analysis
     model_flops: Optional[float] = None
+    peaks: Optional[Peaks] = None   # the target device's; None: costs only
+
+    def _peaks(self) -> Peaks:
+        if self.peaks is None:
+            raise ValueError(f"{self.name}: no device peaks, so no time terms")
+        return self.peaks
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS
+        return self.flops_per_chip / self._peaks().flops
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes_per_chip / HBM_BW
+        return self.hbm_bytes_per_chip / self._peaks().hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_chip / ICI_BW
+        return self.collective_bytes_per_chip / self._peaks().ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -119,7 +136,7 @@ class RooflineRecord:
         """MODEL_FLOPS-at-peak time / roofline step time — the perf score."""
         if not self.model_flops:
             return None
-        ideal = self.model_flops / (self.n_chips * PEAK_FLOPS)
+        ideal = self.model_flops / (self.n_chips * self._peaks().flops)
         return ideal / max(self.roofline_time, 1e-30)
 
     def to_dict(self) -> dict:
@@ -142,10 +159,9 @@ class RooflineRecord:
 
 
 def analyze(name: str, compiled, n_chips: int,
-            model_flops: Optional[float] = None) -> RooflineRecord:
+            model_flops: Optional[float] = None,
+            peaks: Optional[Peaks] = None) -> RooflineRecord:
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older API returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     byt = float(cost.get("bytes accessed", 0.0))
     try:
@@ -168,4 +184,5 @@ def analyze(name: str, compiled, n_chips: int,
         collective_breakdown=coll,
         peak_memory_per_chip=peak,
         model_flops=model_flops,
+        peaks=peaks,
     )

@@ -2,8 +2,8 @@
 
 One VMEM pass over the behavior sequence per (batch, L-tile) grid step:
 
-    S_tile (TL, d) --GEMM--> proj (TL, m) --sign/pack--> sig (TL, G)
-          --one-hot--> (TL, G·U) --GEMMᵀ--> += table (G·U, d)
+    S_tile (TL, d) --GEMM--> proj (m, TL) --sign, pack GEMM--> sig (G·U, TL)
+          --== bucket id--> one-hot (G·U, TL) --GEMM--> += table (G·U, d)
 
 The ``L×m`` code matrix never hits HBM (ETA materializes it; SDIM doesn't
 need to). The bucket "scatter" is expressed as a one-hot matmul so both GEMMs
@@ -17,7 +17,9 @@ the L-grid (sequential innermost dimension). Validated on CPU via
 Contract
 --------
 * **Block specs** — grid ``(B, L/TL)``; per step: seq ``(1, TL, d)``, mask
-  ``(1, TL)``, R ``(m, d)`` replicated, output table ``(1, G·U, d)`` at
+  ``(1, 1, TL)`` of its ``(B, 1, L)`` view (a ``(1, TL)`` block of ``(B, L)``
+  breaks Mosaic's (8, 128)-or-full-dim rule), R ``(m, d)`` replicated,
+  output table ``(1, G·U, d)`` at
   block ``(b, 0, 0)`` (same block every L-step — legal because the
   innermost grid axis is sequential on TPU).
 * **VMEM residency** — one seq tile + R + the full ``(G·U, d)`` table;
@@ -55,33 +57,51 @@ def padded_blocks(n: int, block: int, multiple: int = 8) -> tuple[int, int]:
     return block, -(-n // block) * block
 
 
-def signature_onehot(x: jax.Array, r: jax.Array, *, tau: int, groups: int) -> jax.Array:
-    """In-kernel SimHash: rows x (N, d) -> flat bucket one-hots (N, G·U).
+def _pack_weights(m: int, tau: int, *, buckets_first: bool) -> jax.Array:
+    """The signature-packing operand, built from 2-D iotas only: W[k, j] =
+    2^t where bit k = g·τ + t belongs to the group g = j >> τ of flat bucket
+    column j, else 0. ``bits (N, m) @ W (m, G·U)`` puts each row's group-g
+    signature in all U columns of group g; ``buckets_first`` gives Wᵀ."""
+    shape = ((m // tau) << tau, m) if buckets_first else (m, (m // tau) << tau)
+    k = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if buckets_first else 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if buckets_first else 1)
+    t = k - (j >> tau) * tau
+    own = jnp.logical_and(t >= 0, t < tau)
+    return jnp.where(own, jnp.left_shift(1, jnp.clip(t, 0, tau - 1)),
+                     0).astype(jnp.float32)
 
-    GEMM projection, sign bits, τ-bit packing, then one 1 per group — the
-    shared front half of the encode / query / serve kernels."""
-    proj = jax.lax.dot_general(
-        x, r, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                        # (N, m)
-    bits = (proj >= 0.0).astype(jnp.int32)
-    N = bits.shape[0]
-    grouped = bits.reshape(N, groups, tau)
-    weights = (1 << jax.lax.broadcasted_iota(jnp.int32, (1, 1, tau), 2))
-    sig = jnp.sum(grouped * weights, axis=-1)                # (N, G)
-    U = 1 << tau
-    u_iota = jax.lax.broadcasted_iota(jnp.int32, (N, groups, U), 2)
-    onehot = (sig[:, :, None] == u_iota).astype(jnp.float32)  # (N, G, U)
-    return onehot.reshape(N, groups * U)
+
+def signature_onehot(x: jax.Array, r: jax.Array, *, tau: int,
+                     buckets_first: bool = False) -> jax.Array:
+    """In-kernel SimHash: rows x (N, d) -> flat bucket one-hots (N, G·U), or
+    (G·U, N) with ``buckets_first``.
+
+    GEMM projection, sign bits, then a packing GEMM against ``_pack_weights``
+    and a compare with the bucket id ``j % U`` — one 1 per group. Only 2-D
+    ops, which is what Mosaic lowers: a (N, m) -> (N, G, τ) reshape is an
+    unsupported shape cast on TPU. The projection runs at full fp32
+    precision so the sign bits are the reference's, not a bf16 pass's."""
+    a, b = (r, x) if buckets_first else (x, r)
+    proj = jax.lax.dot_general(                      # (m, N) or (N, m)
+        a, b, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+    bits = (proj >= 0.0).astype(jnp.float32)
+    w = _pack_weights(r.shape[0], tau, buckets_first=buckets_first)
+    a, b = (w, bits) if buckets_first else (bits, w)
+    sig = jnp.dot(a, b, preferred_element_type=jnp.float32)
+    bucket = jax.lax.broadcasted_iota(
+        jnp.int32, sig.shape, 0 if buckets_first else 1) & ((1 << tau) - 1)
+    return (sig == bucket.astype(jnp.float32)).astype(jnp.float32)
 
 
 def encode_tile(s: jax.Array, valid: jax.Array, r: jax.Array,
-                *, tau: int, groups: int) -> jax.Array:
-    """One L-tile's bucket contribution: (TL, d) x (TL,) mask -> (G·U, d)."""
-    onehot = signature_onehot(s, r, tau=tau, groups=groups)
-    onehot = onehot * valid[:, None].astype(jnp.float32)
-    return jax.lax.dot_general(
-        onehot, s, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+                *, tau: int) -> jax.Array:
+    """One L-tile's bucket contribution: (TL, d) x (1, TL) mask -> (G·U, d).
+    The one-hot is built buckets-first so the lane-major mask row scales
+    its columns without a transpose."""
+    onehot = signature_onehot(s, r, tau=tau, buckets_first=True) \
+        * valid.astype(jnp.float32)
+    return jnp.dot(onehot, s, preferred_element_type=jnp.float32)
 
 
 def query_tile(q: jax.Array, tnorm: jax.Array, r: jax.Array,
@@ -89,12 +109,18 @@ def query_tile(q: jax.Array, tnorm: jax.Array, r: jax.Array,
     """One C-tile's interest read: (TC, d) x ℓ2-normalized table (G·U, d) ->
     (TC, d). The one-hot GEMM gathers each group's bucket AND sums over
     groups in a single MXU contraction (Eq. 12's mean, times G)."""
-    onehot = signature_onehot(q, r, tau=tau, groups=groups)
-    gathered = jax.lax.dot_general(
-        onehot, tnorm, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    onehot = signature_onehot(q, r, tau=tau)
+    gathered = jnp.dot(onehot, tnorm, preferred_element_type=jnp.float32)
     return gathered / groups
+
+
+def row_to_column(v: jax.Array) -> jax.Array:
+    """(1, n) -> (n, 1) with 2-D ops only: mask an (n, n) broadcast to its
+    diagonal and reduce over lanes (exact: one nonzero per row)."""
+    n = v.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, v, 0.0), axis=1, keepdims=True)
 
 
 def l2_normalize_rows(t: jax.Array) -> jax.Array:
@@ -102,7 +128,7 @@ def l2_normalize_rows(t: jax.Array) -> jax.Array:
     return t / norm
 
 
-def _encode_kernel(seq_ref, mask_ref, r_ref, table_ref, *, tau: int, groups: int):
+def _encode_kernel(seq_ref, mask_ref, r_ref, table_ref, *, tau: int):
     li = pl.program_id(1)
 
     @pl.when(li == 0)
@@ -111,7 +137,7 @@ def _encode_kernel(seq_ref, mask_ref, r_ref, table_ref, *, tau: int, groups: int
 
     s = seq_ref[0].astype(jnp.float32)                       # (TL, d)
     r = r_ref[...].astype(jnp.float32)                       # (m, d)
-    table_ref[0] += encode_tile(s, mask_ref[0], r, tau=tau, groups=groups)
+    table_ref[0] += encode_tile(s, mask_ref[0], r, tau=tau)
 
 
 def bse_encode(
@@ -135,15 +161,15 @@ def bse_encode(
     mask = pad_axis(mask, 1, L_pad)
 
     out = pl.pallas_call(
-        functools.partial(_encode_kernel, tau=tau, groups=G),
+        functools.partial(_encode_kernel, tau=tau),
         grid=(B, L_pad // block_l),
         in_specs=[
             pl.BlockSpec((1, block_l, d), lambda b, l: (b, l, 0)),
-            pl.BlockSpec((1, block_l), lambda b, l: (b, l)),
+            pl.BlockSpec((1, 1, block_l), lambda b, l: (b, 0, l)),
             pl.BlockSpec((m, d), lambda b, l: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G * U, d), lambda b, l: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, G * U, d), jnp.float32),
         interpret=interpret,
-    )(seq, mask.astype(seq.dtype), R)
+    )(seq, mask.astype(jnp.float32)[:, None, :], R)
     return out.reshape(B, G, U, d)

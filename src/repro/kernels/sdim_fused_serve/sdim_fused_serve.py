@@ -16,7 +16,7 @@ Because the innermost grid axis is sequential on TPU, Pallas double-buffers
 the streamed store blocks: user b+1's row is DMAing HBM→VMEM while user b's
 candidates are scoring on the MXU. The (B, G, U, d) intermediate never
 exists, and for int8/fp8 stores only the QUANTIZED bytes move — the per-row
-fp32 ``scales`` ride along as a (1, G·U) block and the dequantize happens
+fp32 ``scales`` ride along as a (1, 1, G·U) block and the dequantize happens
 in VMEM, so the HBM traffic per user is ~(d+4)/(4d) of the fp32 path.
 
 Dequantize-then-normalize is the oracle contract, though Eq. 12's row
@@ -28,7 +28,9 @@ Contract
 * **Block specs** — ``PrefetchScalarGridSpec`` with the (B,) slot vector
   scalar-prefetched; grid ``(B, C/TC)``; per step: store row ``(1, G·U, d)``
   selected by ``slots[b]`` (the gather is the block index map), scales
-  ``(1, G·U)`` at the same slot (quantized stores only), q ``(1, TC, d)``,
+  ``(1, 1, G·U)`` of their ``(N, 1, G·U)`` view at the same slot (quantized
+  stores only; a ``(1, G·U)`` block of ``(N, G·U)`` breaks Mosaic's
+  (8, 128)-or-full-dim rule), q ``(1, TC, d)``,
   R ``(m, d)`` replicated; output ``(1, TC, d)``.
 * **VMEM residency** — the dequantized, ℓ2-normalized row lives in a
   ``(G·U, d)`` fp32 scratch computed once at ``c == 0`` and reused by every
@@ -52,7 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.sdim_bucket.sdim_bucket import (
-    l2_normalize_rows, pad_axis, padded_blocks, query_tile)
+    l2_normalize_rows, pad_axis, padded_blocks, query_tile, row_to_column)
 
 
 def _fused_kernel(slots_ref, q_ref, store_ref, r_ref, out_ref, tnorm_ref,
@@ -74,9 +76,9 @@ def _fused_kernel_quant(slots_ref, q_ref, store_ref, scales_ref, r_ref,
 
     @pl.when(ci == 0)
     def _prep():
-        rows = (store_ref[0].astype(jnp.float32)
-                * scales_ref[0].astype(jnp.float32)[:, None])
-        tnorm_ref[...] = l2_normalize_rows(rows)
+        rows = store_ref[0].astype(jnp.float32)              # (G·U, d)
+        tnorm_ref[...] = l2_normalize_rows(
+            rows * row_to_column(scales_ref[0].astype(jnp.float32)))
 
     q = q_ref[0].astype(jnp.float32)                         # (TC, d)
     r = r_ref[...].astype(jnp.float32)                       # (m, d)
@@ -114,8 +116,8 @@ def sdim_fused_serve(
     kernel = _fused_kernel
     if scales is not None:
         in_specs.append(
-            pl.BlockSpec((1, G * U), lambda b, c, slots: (slots[b], 0)))
-        operands.append(scales.reshape(N, G * U))
+            pl.BlockSpec((1, 1, G * U), lambda b, c, slots: (slots[b], 0, 0)))
+        operands.append(scales.reshape(N, 1, G * U))
         kernel = _fused_kernel_quant
     in_specs.append(pl.BlockSpec((m, d), lambda b, c, slots: (0, 0)))
     operands.append(R)

@@ -18,7 +18,8 @@ padded candidates are computed on zeros and sliced off).
 Contract
 --------
 * **Block specs** — grid ``(B, L/TL + 1)``: steps ``l < nL`` stream seq
-  tiles ``(1, TL, d)`` + mask ``(1, TL)``; the final step reads the whole
+  tiles ``(1, TL, d)`` + mask ``(1, 1, TL)`` of its ``(B, 1, L)`` view; the
+  final step reads the whole
   candidate block ``(1, C_pad, d)`` and writes the output ``(1, C_pad, d)``;
   R ``(m, d)`` replicated throughout.
 * **VMEM residency** — the bucket table is a ``(G·U, d)`` scratch
@@ -56,7 +57,7 @@ def _serve_kernel(q_ref, seq_ref, mask_ref, r_ref, out_ref, table_ref,
     @pl.when(li < n_l_steps)
     def _encode():
         s = seq_ref[0].astype(jnp.float32)                   # (TL, d)
-        table_ref[...] += encode_tile(s, mask_ref[0], r, tau=tau, groups=groups)
+        table_ref[...] += encode_tile(s, mask_ref[0], r, tau=tau)
 
     @pl.when(li == n_l_steps)
     def _query():
@@ -95,13 +96,13 @@ def bse_serve(
             pl.BlockSpec((1, C_pad, d), lambda b, l: (b, 0, 0)),
             pl.BlockSpec((1, block_l, d),
                          lambda b, l: (b, jnp.minimum(l, n_l - 1), 0)),
-            pl.BlockSpec((1, block_l),
-                         lambda b, l: (b, jnp.minimum(l, n_l - 1))),
+            pl.BlockSpec((1, 1, block_l),
+                         lambda b, l: (b, 0, jnp.minimum(l, n_l - 1))),
             pl.BlockSpec((m, d), lambda b, l: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, C_pad, d), lambda b, l: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, C_pad, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((G * U, d), jnp.float32)],
         interpret=interpret,
-    )(q, seq, mask.astype(seq.dtype), R)
+    )(q, seq, mask.astype(jnp.float32)[:, None, :], R)
     return out[:, :C]

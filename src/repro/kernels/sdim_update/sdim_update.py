@@ -28,7 +28,8 @@ Contract
 * **Block specs** — ``PrefetchScalarGridSpec`` with the sorted slot vector
   scalar-prefetched; grid ``(B, E/TE)``; per step: store row ``(1, G·U, d)``
   selected by ``slots[b]`` (the gather IS the block index map), events
-  ``(1, TE, d)``, mask ``(1, TE)``, R ``(m, d)``; output row ``(1, G·U, d)``.
+  ``(1, TE, d)``, mask ``(1, 1, TE)`` of its ``(B, 1, E)`` view, R ``(m, d)``;
+  output row ``(1, G·U, d)``.
 * **VMEM residency** — a ``(G·U, d)`` running-total scratch accumulator,
   re-seeded from the store row whenever the (sorted) slot changes and
   carried across duplicate-slot rows. ``block_e`` (= engine ``block_l``)
@@ -55,7 +56,7 @@ from repro.kernels.sdim_bucket.sdim_bucket import (
 
 
 def _update_kernel(slots_ref, store_ref, ev_ref, mask_ref, r_ref, out_ref,
-                   acc_ref, *, tau: int, groups: int):
+                   acc_ref, *, tau: int):
     b = pl.program_id(0)
     e = pl.program_id(1)
     slot = slots_ref[b]
@@ -70,7 +71,7 @@ def _update_kernel(slots_ref, store_ref, ev_ref, mask_ref, r_ref, out_ref,
 
     r = r_ref[...].astype(jnp.float32)                       # (m, d)
     s = ev_ref[0].astype(jnp.float32)                        # (TE, d)
-    acc_ref[...] += encode_tile(s, mask_ref[0], r, tau=tau, groups=groups)
+    acc_ref[...] += encode_tile(s, mask_ref[0], r, tau=tau)
     out_ref[0] = acc_ref[...]
 
 
@@ -106,18 +107,18 @@ def sdim_update(
         in_specs=[
             pl.BlockSpec((1, G * U, d), lambda b, e, slots: (slots[b], 0, 0)),
             pl.BlockSpec((1, block_e, d), lambda b, e, slots: (b, e, 0)),
-            pl.BlockSpec((1, block_e), lambda b, e, slots: (b, e)),
+            pl.BlockSpec((1, 1, block_e), lambda b, e, slots: (b, 0, e)),
             pl.BlockSpec((m, d), lambda b, e, slots: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G * U, d), lambda b, e, slots: (b, 0, 0)),
         scratch_shapes=[pltpu.VMEM((G * U, d), jnp.float32)],
     )
     rows = pl.pallas_call(
-        functools.partial(_update_kernel, tau=tau, groups=G),
+        functools.partial(_update_kernel, tau=tau),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, G * U, d), jnp.float32),
         interpret=interpret,
-    )(slots_s, store2d, events, mask.astype(events.dtype), R)
+    )(slots_s, store2d, events, mask.astype(jnp.float32)[:, None, :], R)
 
     # row b holds the RUNNING total of its slot: only the last occurrence has
     # the full sum, so earlier duplicates are routed to a trash row

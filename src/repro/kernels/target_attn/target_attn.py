@@ -8,6 +8,8 @@ HBM — the TPU adaptation of FlashAttention specialized to *target* attention
                  acc = acc·e^{m−m'} + e^{s−m'}·V ; l = l·e^{m−m'} + rowsum
 
 Scratch (VMEM): running max (C,1), denom (C,1), accumulator (C,d), all fp32.
+The mask streams as ``(1, 1, TL)`` blocks of its ``(B, 1, L)`` view: a
+``(1, TL)`` block of ``(B, L)`` breaks Mosaic's (8, 128)-or-full-dim rule.
 """
 from __future__ import annotations
 
@@ -38,7 +40,7 @@ def _ta_kernel(q_ref, seq_ref, mask_ref, out_ref, m_ref, l_ref, acc_ref):
     s = jax.lax.dot_general(
         q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                                 # (TC, TL)
-    valid = mask_ref[0][None, :] > 0
+    valid = mask_ref[0] > 0                                   # (1, TL)
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]                                       # (TC, 1)
@@ -77,7 +79,7 @@ def target_attention_flash(
         in_specs=[
             pl.BlockSpec((1, block_c, d), lambda b, c, l: (b, c, 0)),
             pl.BlockSpec((1, block_l, d), lambda b, c, l: (b, l, 0)),
-            pl.BlockSpec((1, block_l), lambda b, c, l: (b, l)),
+            pl.BlockSpec((1, 1, block_l), lambda b, c, l: (b, 0, l)),
         ],
         out_specs=pl.BlockSpec((1, block_c, d), lambda b, c, l: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, C, d), jnp.float32),
@@ -87,4 +89,4 @@ def target_attention_flash(
             pltpu.VMEM((block_c, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, seq, mask.astype(seq.dtype))
+    )(q, seq, mask.astype(jnp.float32)[:, None, :])

@@ -32,17 +32,18 @@ import traceback
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 from repro.configs import registry                       # noqa: E402
 from repro.distributed import roofline as rl             # noqa: E402
 from repro.launch import flops as flops_lib              # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh       # noqa: E402
 from repro.launch.specs import build_cell, has_scans     # noqa: E402
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..", "results", "dryrun")
 RESULTS_DIR = os.path.abspath(RESULTS_DIR)
+# the pods these cells are sized for are v5e: their roofline is a prediction
+# for that chip (the cells compile on virtual CPU devices; nothing is timed)
+TARGET_PEAKS = rl.PEAKS[rl.V5E]
 
 
 def out_path(mesh_tag: str, arch: str, shape: str, variant: str) -> str:
@@ -87,7 +88,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, variant: str = "baseline",
             with mesh:
                 cu = jax.jit(cell_u.step_fn, donate_argnums=cell_u.donate) \
                     .lower(*cell_u.abstract_args).compile()
-            recs[k] = rl.analyze(cell.name, cu, n_chips)
+            recs[k] = rl.analyze(cell.name, cu, n_chips)   # costs only
         L = lm_scan_depth(arch)
 
         def extrap(v1, v2):
@@ -108,6 +109,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, variant: str = "baseline",
                 for op in recs[k1].collective_breakdown},
             peak_memory_per_chip=0.0,   # memory comes from pass 1
             model_flops=mf,
+            peaks=TARGET_PEAKS,
         )
     elif has_scans(arch, shape):
         cell_u = build_cell(arch, shape, mesh, variant=variant, unroll=True)
@@ -115,9 +117,11 @@ def run_cell(arch: str, shape: str, multi_pod: bool, variant: str = "baseline",
             cost_compiled = jax.jit(
                 cell_u.step_fn, donate_argnums=cell_u.donate
             ).lower(*cell_u.abstract_args).compile()
-        record = rl.analyze(cell.name, cost_compiled, n_chips, model_flops=mf)
+        record = rl.analyze(cell.name, cost_compiled, n_chips,
+                            model_flops=mf, peaks=TARGET_PEAKS)
     else:
-        record = rl.analyze(cell.name, compiled, n_chips, model_flops=mf)
+        record = rl.analyze(cell.name, compiled, n_chips, model_flops=mf,
+                            peaks=TARGET_PEAKS)
     out = record.to_dict()
     out.update({
         "arch": arch, "shape": shape, "variant": variant, "mesh": mesh_tag,
@@ -164,6 +168,7 @@ def main() -> None:
     p.add_argument("--all", action="store_true", help="all 40 cells on this mesh")
     p.add_argument("--force", action="store_true")
     args = p.parse_args()
+    enable_compile_cache()
 
     mesh_tag = "pod2x16x16" if args.multi_pod else "pod16x16"
     if args.all:

@@ -97,6 +97,57 @@ def build_mesh(shards: int, mesh_spec: str = None, err=None):
     return MeshCtx(make_auto_mesh(shape, axes))
 
 
+def build_ctr_server(cfg, *, backend: str = "auto", params=None,
+                     **build_kw):
+    """The serving deployment of one ``CTRConfig``: the model with its SDIM
+    engine on ``backend``, its params (``params``, else a fresh init from
+    key 0) and ``CTRServer.build`` — decoupled BSE + CTR servers for an
+    SDIM interest, inline scoring otherwise. ``build_kw`` goes to
+    ``CTRServer.build``. Returns ``(model, params, server)``."""
+    from repro.models.ctr import CTRModel
+    from repro.serve.ctr_server import CTRServer
+
+    if cfg.interest.kind == "sdim":
+        cfg = dataclasses.replace(
+            cfg, interest=dataclasses.replace(cfg.interest, backend=backend))
+    model = CTRModel(cfg)
+    if params is None:
+        params = model.init(jax.random.PRNGKey(0))
+    mode = "decoupled" if cfg.interest.kind == "sdim" else "inline"
+    return model, params, CTRServer.build(model, params, mode, **build_kw)
+
+
+def synthetic_requests(cfg, n_requests: int, n_candidates: int) -> list:
+    """``handle_requests`` tuples for ``cfg``: request r comes from user
+    ``u{r}``, whose history is ``generate_batch``'s for that user, with
+    ``n_candidates`` candidates drawn from seed 0."""
+    from repro.data.synthetic import SyntheticCTRConfig, generate_batch
+
+    dcfg = SyntheticCTRConfig(hist_len=cfg.long_len, n_items=cfg.n_items,
+                              n_cats=cfg.n_cats)
+    rng = np.random.default_rng(0)
+    out = []
+    for r in range(n_requests):
+        raw = generate_batch(dcfg, 1, r)
+        user = {k: jnp.asarray(v) for k, v in raw.items()
+                if k.startswith("hist")}
+        ci = rng.integers(0, cfg.n_items, n_candidates).astype(np.int32)
+        cc = rng.integers(0, cfg.n_cats, n_candidates).astype(np.int32)
+        out.append((f"u{r}", user, jnp.asarray(ci), jnp.asarray(cc),
+                    jnp.zeros((n_candidates, cfg.ctx_dim))))
+    return out
+
+
+def serve_requests(server, requests: list, micro_batch: int = 1) -> list:
+    """Serve ``requests`` in bursts of ``micro_batch`` (one fetch or fused
+    dispatch plus one scoring dispatch per burst). Returns one (C,) score
+    array per request, ``None`` where admission shed it."""
+    out = []
+    for lo in range(0, len(requests), micro_batch):
+        out += server.handle_requests(requests[lo:lo + micro_batch])
+    return out
+
+
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True, choices=registry.ARCH_IDS)
@@ -187,6 +238,7 @@ def main():
                    help="LM: SDIM bucket-compressed KV decode")
     args = p.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve.quant import TABLE_DTYPES, resolve_table_dtype
     from repro.serve.tiered_store import is_tiered
 
@@ -197,6 +249,7 @@ def main():
                    else " (this jax has no float8_e4m3fn)"))
     table_dtype = resolve_table_dtype(args.table_dtype)
 
+    enable_compile_cache()
     mod = registry.get(args.arch)
     cfg = mod.SMOKE
     tiered = is_tiered(args.hot_capacity, args.store_dir, args.policy,
@@ -268,15 +321,6 @@ def main():
     if tiered and args.hot_capacity is not None and args.hot_capacity < 1:
         p.error(f"--hot-capacity must be >= 1, got {args.hot_capacity}")
     if mod.FAMILY == "recsys":
-        from repro.data.synthetic import SyntheticCTRConfig, generate_batch
-        from repro.models.ctr import CTRModel
-        from repro.serve.ctr_server import CTRServer
-
-        if cfg.interest.kind == "sdim":
-            cfg = dataclasses.replace(
-                cfg, interest=dataclasses.replace(cfg.interest, backend=args.backend))
-        model = CTRModel(cfg)
-        params = model.init(jax.random.PRNGKey(0))
         mode = "decoupled" if cfg.interest.kind == "sdim" else "inline"
         if mode != "decoupled" and (args.mesh or args.shards > 1):
             p.error(f"--shards/--mesh shard the BSE table store, which only "
@@ -305,22 +349,18 @@ def main():
         if tracing:
             from repro.serve.tracing import Tracer
             tracer = Tracer(slow_ms=args.trace_slow_ms)
-        server = CTRServer.build(model, params, mode, mesh=mesh_ctx,
-                                 hot_capacity=args.hot_capacity,
-                                 store_dir=args.store_dir, policy=args.policy,
-                                 warm_capacity=args.warm_capacity,
-                                 table_dtype=table_dtype,
-                                 fused=args.fused_serve,
-                                 async_ingest=args.async_ingest,
-                                 queue_depth=args.queue_depth,
-                                 max_staleness=args.max_staleness,
-                                 max_concurrency=args.max_concurrency,
-                                 rate_limit=args.rate_limit,
-                                 rate_burst=args.rate_burst,
-                                 cold_deadline_s=(
-                                     None if args.cold_deadline_ms is None
-                                     else args.cold_deadline_ms / 1e3),
-                                 tracer=tracer)
+        model, params, server = build_ctr_server(
+            cfg, backend=args.backend, mesh=mesh_ctx,
+            hot_capacity=args.hot_capacity, store_dir=args.store_dir,
+            policy=args.policy, warm_capacity=args.warm_capacity,
+            table_dtype=table_dtype, fused=args.fused_serve,
+            async_ingest=args.async_ingest, queue_depth=args.queue_depth,
+            max_staleness=args.max_staleness,
+            max_concurrency=args.max_concurrency,
+            rate_limit=args.rate_limit, rate_burst=args.rate_burst,
+            cold_deadline_s=(None if args.cold_deadline_ms is None
+                             else args.cold_deadline_ms / 1e3),
+            tracer=tracer)
         bse = server.bse
         profiler = ledger = None
         if profiling:
@@ -338,54 +378,28 @@ def main():
             print(f"BSE table store sharded over "
                   f"{bse.store.n_shards} devices "
                   f"(mesh {dict(mesh_ctx.mesh.shape)})")
-        dcfg = SyntheticCTRConfig(hist_len=cfg.long_len, n_items=cfg.n_items,
-                                  n_cats=cfg.n_cats)
-        rng = np.random.default_rng(0)
-        pending = []  # micro-batch buffer of (req_id, request tuple)
-
-        def report(r, scores):
+        requests = synthetic_requests(cfg, args.requests, args.candidates)
+        if cfg.arch == "wide_deep":
+            # inline arch with sparse fields: scored straight off the model
+            rng = np.random.default_rng(1)
+            apply = jax.jit(model.apply)
+            L = cfg.long_len
+            results = [apply(params, {
+                "hist_items": jnp.broadcast_to(u["hist_items"], (len(ci), L)),
+                "hist_cats": jnp.broadcast_to(u["hist_cats"], (len(ci), L)),
+                "hist_mask": jnp.broadcast_to(u["hist_mask"], (len(ci), L)),
+                "cand_item": ci, "cand_cat": cc, "ctx": ctx,
+                "sparse_ids": jnp.asarray(rng.integers(
+                    0, cfg.field_vocab, (len(ci), cfg.n_sparse)).astype(np.int32))})
+                for _, u, ci, cc, ctx in requests]
+        else:
+            results = serve_requests(server, requests, args.micro_batch)
+        for r, scores in enumerate(results):
             if scores is None:          # shed by admission control — counted
                 print(f"req {r}: SHED (admission control)")
             else:
                 print(f"req {r}: top candidate {int(jnp.argmax(scores))} "
                       f"(score {float(jnp.max(scores)):+.3f})")
-
-        def flush():
-            for (r, _), scores in zip(pending,
-                                      server.handle_requests([q for _, q in pending])):
-                report(r, scores)
-            pending.clear()
-
-        for r in range(args.requests):
-            raw = generate_batch(dcfg, 1, r)
-            user = {k: jnp.asarray(v) for k, v in raw.items() if k.startswith("hist")}
-            ci = jnp.asarray(rng.integers(0, cfg.n_items, args.candidates).astype(np.int32))
-            cc = jnp.asarray(rng.integers(0, cfg.n_cats, args.candidates).astype(np.int32))
-            kw = {}
-            if cfg.arch == "wide_deep":
-                kw["sparse_ids"] = jnp.asarray(rng.integers(
-                    0, cfg.field_vocab, (args.candidates, cfg.n_sparse)).astype(np.int32))
-                scores = jax.jit(model.apply)(params, {
-                    "hist_items": jnp.broadcast_to(user["hist_items"], (args.candidates, cfg.long_len)),
-                    "hist_cats": jnp.broadcast_to(user["hist_cats"], (args.candidates, cfg.long_len)),
-                    "hist_mask": jnp.broadcast_to(user["hist_mask"], (args.candidates, cfg.long_len)),
-                    "cand_item": ci, "cand_cat": cc,
-                    "ctx": jnp.zeros((args.candidates, cfg.ctx_dim)), **kw})
-            elif args.micro_batch > 1:
-                pending.append((r, (f"u{r}", user, ci, cc,
-                                    jnp.zeros((args.candidates, cfg.ctx_dim)))))
-                if len(pending) == args.micro_batch:
-                    flush()
-                continue
-            else:
-                # handle_request is a 1-burst through the batch path:
-                # admission, metrics and tracing apply uniformly
-                scores = server.handle_request(
-                    f"u{r}", user, ci, cc,
-                    jnp.zeros((args.candidates, cfg.ctx_dim)))
-            report(r, scores)
-        if pending:
-            flush()
         if bse and bse.async_ingest is not None:
             bse.async_ingest.stop(flush=True)   # quiesce before reporting
             ist = bse.async_ingest.stats

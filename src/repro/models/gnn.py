@@ -23,7 +23,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.nn.layers import LayerNorm, Linear, MLP
 from repro.nn.module import KeyGen
@@ -80,11 +79,11 @@ def _layer_apply(params, h, e, src, dst, edge_mask, n_nodes: int, d: int,
             n = jax.ops.segment_sum(gate_l, dst_l, n_nodes)
             return jax.lax.psum((a, n), axes)
 
-        agg, norm = shard_map(
+        agg, norm = jax.shard_map(
             scatter, mesh=mesh,
             in_specs=(P(axes), P(axes), P(axes)),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(msg, gate, dst)
 
     h_agg = agg / (norm + 1e-6)

@@ -20,7 +20,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.nn.layers import Linear, RMSNorm
 from repro.nn.module import KeyGen
@@ -394,11 +393,9 @@ class MLAttention:
 # ---------------------------------------------------------------------------
 def _combined_axis_index(axes):
     """Linear shard index over a tuple of mesh axes (row-major)."""
-    from repro.distributed.compat import axis_size
-
     idx = jax.lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -458,13 +455,13 @@ def gqa_sp_decode_attention(
     kv_spec = P(b, seq_axes, None, None)
     q_spec = P(b, None, None, None)
 
-    m_g, l_g, acc_g = shard_map(
+    m_g, l_g, acc_g = jax.shard_map(
         local, mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P()),
         out_specs=(P(b, None, None, None),
                    P(b, None, None, None),
                    P(b, None, None, None, None)),
-        check_rep=False,
+        check_vma=False,
     )(q, k_cache, v_cache, cache_len)
 
     # merge the current token (always visible to itself)
@@ -518,12 +515,12 @@ def mla_sp_decode_attention(
         return m_g, l_g, acc_g
 
     b = batch_axes if batch_axes else None
-    m_g, l_g, acc_g = shard_map(
+    m_g, l_g, acc_g = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b, None, None, None), P(b, None, None, None),
                   P(b, seq_axes, None), P(b, seq_axes, None), P()),
         out_specs=(P(b, None, None), P(b, None, None), P(b, None, None, None)),
-        check_rep=False,
+        check_vma=False,
     )(q_lat, q_rope, ckv_cache, krope_cache, cache_len)
 
     s_new = (jnp.einsum("bthr,bsr->bhts", q_lat.astype(jnp.float32), c_new.astype(jnp.float32))

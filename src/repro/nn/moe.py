@@ -26,7 +26,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.nn.layers import ACTIVATIONS
 from repro.nn.module import KeyGen, lecun_normal
@@ -193,7 +192,7 @@ class MoELayer:
                 ).reshape(Bl, Tl, d)
                 return jax.lax.psum(out, model_axis)
 
-            routed = shard_map(
+            routed = jax.shard_map(
                 routed_fn,
                 mesh=ctx.mesh,
                 in_specs=(
@@ -203,7 +202,7 @@ class MoELayer:
                     P(model_axis, None, None),
                 ),
                 out_specs=tok_spec,
-                check_rep=False,
+                check_vma=False,
             )(x, topk_idx, topk_w, params["experts"])
 
         if self.n_shared:

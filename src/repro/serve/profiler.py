@@ -22,10 +22,13 @@ bound. Two instruments fix that:
     steady-state sample. Per *kernel* it captures ``cost_analysis()``
     flops / bytes once, from an AOT ``lower().compile()`` of the first
     call's arguments — BEFORE the call runs, so donated buffers are still
-    valid — plus the analytical ``roofline.analyze`` record for the same
-    executable. Measured arithmetic intensity (flops/byte) and
-    achieved-vs-peak fraction then sit next to the model's prediction in
-    ``roofline_report()``.
+    valid — plus, on a device whose peaks ``roofline.PEAKS`` lists, the
+    analytical ``roofline.analyze`` record for the same executable.
+    Measured arithmetic intensity (flops/byte) and achieved-vs-peak
+    fraction then sit next to the model's prediction in
+    ``roofline_report()``. On any other device (the CPU among them) there
+    is no prediction and ``pct_peak`` is ``None``: a host timing is never
+    compared with a chip's peaks.
 
 ``MemoryLedger``
     Byte accounting keyed by ``(store, tier, dtype)`` across every grow /
@@ -92,12 +95,13 @@ class KernelRecord:
         return self.flops / self.bytes if self.bytes > 0 else 0.0
 
     @property
-    def pct_peak(self) -> float:
+    def pct_peak(self) -> Optional[float]:
         """Achieved fraction of the analytical roofline: predicted
-        best-case time over measured time, clamped to [0, 1]. 0.0 until
-        both a timed call and a prediction exist."""
+        best-case time over measured time, clamped to [0, 1]. ``None``
+        until both a timed call and a prediction exist — and there is no
+        prediction off a device with known peaks."""
         if self.predicted is None or not self.n_calls:
-            return 0.0
+            return None
         ideal = self.predicted.roofline_time
         if ideal <= 0.0 or self.mean_s <= 0.0:
             return 0.0
@@ -109,14 +113,15 @@ class KernelRecord:
         self.min_s = min(self.min_s, dt)
         self.max_s = max(self.max_s, dt)
 
-    def capture_cost(self, compiled, n_chips: int) -> None:
+    def capture_cost(self, compiled, n_chips: int,
+                     peaks: Optional[roofline.Peaks]) -> None:
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):          # older API returns [dict]
-            cost = cost[0]
         # XLA reports -1 for terms it cannot attribute; clamp to 0
         self.flops = max(float(cost.get("flops", 0.0)), 0.0)
         self.bytes = max(float(cost.get("bytes accessed", 0.0)), 0.0)
-        self.predicted = roofline.analyze(self.name, compiled, n_chips)
+        if peaks is not None:
+            self.predicted = roofline.analyze(self.name, compiled, n_chips,
+                                              peaks=peaks)
 
     def to_dict(self) -> dict:
         d = {
@@ -148,7 +153,9 @@ class KernelProfiler:
     Attach with ``profiler.attach(engine)`` (sets ``engine.profiler``);
     every subsequent engine dispatch routes through ``profile``. ``clock``
     is any monotonic ``() -> seconds`` (``VirtualClock`` in tests);
-    ``n_chips`` feeds the analytical roofline; ``metrics`` receives
+    ``n_chips`` feeds the analytical roofline, whose peaks are those
+    ``roofline.PEAKS`` lists for this process's device kind (none on a
+    device it does not list); ``metrics`` receives
     ``kernel.<name>_ms`` histograms + a ``kernel.compiles`` counter;
     ``tracer`` gets a ``kernel.<name>`` span per profiled dispatch
     carrying ``flops`` / ``bytes`` / ``ai`` attrs once known."""
@@ -162,7 +169,7 @@ class KernelProfiler:
         self.metrics = metrics
         self.tracer = tracer
         self.records: dict[str, KernelRecord] = {}
-        self._seen_fns: set[int] = set()    # warmup fallback (no _cache_size)
+        self.peaks = roofline.PEAKS.get(jax.devices()[0].device_kind)
 
     def attach(self, engine) -> Any:
         """Wire this profiler into an ``SDIMEngine``; returns the engine."""
@@ -173,30 +180,20 @@ class KernelProfiler:
         """Run one jitted dispatch under measurement: AOT cost capture on
         first sight of the kernel (argument buffers are still intact —
         donation happens in the real call below), block-until-ready wall
-        timing, and jit-warmup exclusion via ``fn._cache_size()`` growth
-        (first-call heuristic when the callable does not expose it)."""
+        timing, and jit-warmup exclusion via ``fn._cache_size()`` growth.
+        A dispatch that fails to compile raises here."""
         rec = self.records.get(name)
         if rec is None:
             rec = self.records[name] = KernelRecord(name)
-        if rec.predicted is None and rec.n_compiles == 0:
-            try:
-                rec.capture_cost(fn.lower(*args, **kwargs).compile(),
-                                 self.n_chips)
-            except Exception:
-                pass    # interpret-mode / exotic backends may not lower AOT
-        size = getattr(fn, "_cache_size", None)
-        before = size() if size is not None else -1
+            rec.capture_cost(fn.lower(*args, **kwargs).compile(),
+                             self.n_chips, self.peaks)
+        before = fn._cache_size()
         with maybe_span(self.tracer, f"kernel.{name}") as sp:
             t0 = self.clock()
             out = fn(*args, **kwargs)
             jax.block_until_ready(out)
             dt = self.clock() - t0
-            if size is not None:
-                compiled_now = size() > before
-            else:
-                compiled_now = id(fn) not in self._seen_fns
-                self._seen_fns.add(id(fn))
-            if compiled_now:
+            if fn._cache_size() > before:
                 rec.n_compiles += 1
                 sp.set(compile=True)
                 if self.metrics is not None:
@@ -232,10 +229,11 @@ class KernelProfiler:
                 bound = rec.predicted.bottleneck
             else:
                 pred, bound = f"{'-':>9}", "-"
+            pct = "-" if rec.pct_peak is None else f"{rec.pct_peak:.3f}"
             lines.append(
                 f"{name:<20} {rec.n_calls:>5} {rec.time_ms:>9.4f} "
                 f"{rec.flops:>10.3g} {rec.bytes:>10.3g} {rec.ai:>7.3f} "
-                f"{rec.pct_peak:>8.3f} {pred} {bound:<10}")
+                f"{pct:>8} {pred} {bound:<10}")
         if not self.records:
             lines.append("(no profiled dispatches)")
         return "\n".join(lines)

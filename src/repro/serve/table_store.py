@@ -50,7 +50,6 @@ from typing import Any, Iterator, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.distributed.mesh_ctx import MeshCtx
@@ -396,9 +395,10 @@ def _sharded_ops(mesh, axis: str, rank: int = 3):
             out = jax.lax.psum(rows, axis)
             return out.astype(block.dtype) if integer else out
 
-        return shard_map(body, mesh=mesh, in_specs=(rowspec, rep1, rep1),
-                         out_specs=repn, check_rep=False)(
-                             data, shard_ids, locals_)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(rowspec, rep1, rep1),
+                             out_specs=repn, check_vma=False)(
+            data, shard_ids, locals_)
 
     def scatter(data, shard_ids, locals_, rows):
         cap = data.shape[1]
@@ -408,17 +408,17 @@ def _sharded_ops(mesh, axis: str, rank: int = 3):
             return block[0].at[tgt].set(rw.astype(block.dtype),
                                         mode="drop")[None]
 
-        return shard_map(body, mesh=mesh,
-                         in_specs=(rowspec, rep1, rep1, repn),
-                         out_specs=rowspec, check_rep=False)(
-                             data, shard_ids, locals_, rows)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(rowspec, rep1, rep1, repn),
+                             out_specs=rowspec, check_vma=False)(
+            data, shard_ids, locals_, rows)
 
     def grow(data):
         def body(block):
             return jnp.concatenate([block, jnp.zeros_like(block)], axis=1)
 
-        return shard_map(body, mesh=mesh, in_specs=(rowspec,),
-                         out_specs=rowspec, check_rep=False)(data)
+        return jax.shard_map(body, mesh=mesh, in_specs=(rowspec,),
+                             out_specs=rowspec, check_vma=False)(data)
 
     # grow's output is twice its input — donation could never alias, it
     # would only emit "donated buffers were not usable" warnings. The

@@ -147,12 +147,11 @@ print(json.dumps({"equal": ok, "sharded": str(sh.spec)}))
 
 def test_compressed_psum_on_real_axis():
     out = run_sub(PREAMBLE + """
-from jax.experimental.shard_map import shard_map
 from repro.train.compression import compressed_psum
 g = jax.random.normal(jax.random.PRNGKey(0), (8, 16))
-f = shard_map(lambda t: compressed_psum({"g": t}, "data")["g"], mesh=mesh,
-              in_specs=(P("data", None),), out_specs=P("data", None),
-              check_rep=False)
+f = jax.shard_map(lambda t: compressed_psum({"g": t}, "data")["g"],
+                  mesh=mesh, in_specs=(P("data", None),),
+                  out_specs=P("data", None), check_vma=False)
 with mesh:
     out = jax.jit(f)(g)
 # per-shard mean of the two data shards, within int8 error

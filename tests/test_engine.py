@@ -157,6 +157,16 @@ def test_auto_backend_resolves():
     assert SDIMEngine(cfg).backend == resolve_backend("auto")
 
 
+def test_interpret_mode_refused_on_tpu(monkeypatch):
+    """On a TPU the kernels must compile: an explicit interpret=True is an
+    error there, and the default never interprets."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="interpret"):
+        _ = SDIMEngine(EngineConfig(backend="pallas", interpret=True)).interpret
+    eng = SDIMEngine(EngineConfig(backend="auto"))
+    assert eng.backend == "pallas" and eng.interpret is False
+
+
 def test_srht_family_is_a_real_hash_family():
     """Densified SRHT projections equal the FWHT-chain projections, so the
     engine's srht family IS the O(m·log d) family, just GEMM-materialized."""

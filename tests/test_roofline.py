@@ -69,6 +69,7 @@ def test_roofline_record_terms_and_bottleneck():
         collective_bytes_per_chip=50e9 * 2,  # 2 s
         collective_breakdown={}, peak_memory_per_chip=0.0,
         model_flops=197e12 * 256,       # ideal == compute term
+        peaks=rl.PEAKS[rl.V5E],
     )
     assert abs(r.t_compute - 1.0) < 1e-9
     assert abs(r.t_memory - 0.5) < 1e-9
@@ -76,6 +77,21 @@ def test_roofline_record_terms_and_bottleneck():
     assert r.bottleneck == "collective"
     assert abs(r.roofline_time - 2.0) < 1e-9
     assert abs(r.roofline_fraction - 0.5) < 1e-9  # ideal 1 s / roofline 2 s
+
+
+def test_peaks_are_keyed_by_device_kind():
+    """Time terms exist only with the peaks of a listed device kind; a
+    record without peaks keeps its costs and refuses to be timed."""
+    assert rl.PEAKS[rl.V5E].flops == 197e12 and rl.PEAKS[rl.V5E].hbm_bw == 819e9
+    assert "cpu" not in rl.PEAKS
+    r = rl.RooflineRecord(
+        name="t", n_chips=1, flops_per_chip=1.0, hbm_bytes_per_chip=2.0,
+        collective_bytes_per_chip=0.0, collective_breakdown={},
+        peak_memory_per_chip=0.0)
+    assert r.hbm_bytes_per_chip == 2.0
+    for term in ("t_compute", "t_memory", "roofline_time"):
+        with pytest.raises(ValueError, match="no device peaks"):
+            getattr(r, term)
 
 
 def test_unrolled_cost_linear_in_depth():

@@ -13,7 +13,7 @@ schema 3 additionally requires the ``trace`` section (span-coverage
 fraction + jit-compile span count from the traced replay, ISSUE 9) and a
 ``git_rev`` stamp; schema 4 additionally requires the ``profile`` section
 (measured per-kernel time / cost_analysis flops+bytes / arithmetic
-intensity with pct_peak in [0,1], plus the memory-ledger tier bytes,
+intensity with pct_peak in [0,1] or null, plus the memory-ledger tier bytes,
 ISSUE 10); schema 1/2/3 files remain readable for back-compat with
 older checkouts. Thresholds (1.5x speedup, 3.5x bytes, 1e-3 AUC
 gap, 1.2x under-ingest p95) are
@@ -189,7 +189,8 @@ def check_profile(profile) -> str:
     """Validate a schema-4 ``profile`` block (also called standalone by
     ``tools/profile_report.py --smoke``): ``per_kernel`` must be a
     non-empty dict of kernels with non-negative time/flops/bytes/ai and
-    ``pct_peak`` in [0,1] (each ``predicted`` sub-block, when present,
+    ``pct_peak`` in [0,1], or null where the device has no listed peaks
+    (each ``predicted`` sub-block, when present,
     non-negative as well), and ``mem`` must report non-negative
     hot/warm/cold tier bytes. Returns a one-line summary; raises
     ``Malformed`` on any structural problem."""
@@ -207,7 +208,8 @@ def check_profile(profile) -> str:
         _num(rec, "flops", lo=0, where=where)
         _num(rec, "bytes", lo=0, where=where)
         _num(rec, "ai", lo=0, where=where)
-        _num(rec, "pct_peak", lo=0.0, hi=1.0, where=where)
+        if rec.get("pct_peak") is not None:
+            _num(rec, "pct_peak", lo=0.0, hi=1.0, where=where)
         pred = rec.get("predicted")
         if pred is not None:
             if not isinstance(pred, dict):

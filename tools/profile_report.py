@@ -55,13 +55,15 @@ def render(profile: dict) -> str:
         pred = rec.get("predicted") or {}
         pred_ms = pred.get("roofline_ms")
         pred_col = f"{pred_ms:>9.4f}" if pred_ms is not None else f"{'-':>9}"
+        pct = rec.get("pct_peak")
+        pct_col = f"{pct:>8.3f}" if pct is not None else f"{'-':>8}"
         lines.append(
             f"{name:<20} {rec.get('calls', 0):>5} "
             f"{rec.get('time_ms', 0.0):>9.4f} "
             f"{rec.get('flops', 0.0):>10.3g} "
             f"{rec.get('bytes', 0.0):>10.3g} "
             f"{rec.get('ai', 0.0):>7.3f} "
-            f"{rec.get('pct_peak', 0.0):>8.3f} "
+            f"{pct_col} "
             f"{pred_col} {pred.get('bottleneck', '-'):<10}")
     if not pk:
         lines.append("(no profiled kernels)")
