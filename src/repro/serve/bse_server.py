@@ -81,7 +81,7 @@ import numpy as np
 from repro.core.engine import SDIMEngine
 from repro.serve.metrics import MetricsRegistry, observe_ms
 from repro.serve.table_store import ShardedTableStore, TableStore
-from repro.serve.tracing import NOOP_SPAN, Tracer
+from repro.serve.tracing import NOOP_SPAN, Tracer, maybe_span
 from repro.serve.tiered_store import (TieredTableStore, _atomic_json,
                                       _atomic_npz, burst_cap, burst_chunks,
                                       is_tiered)
@@ -301,7 +301,8 @@ class BSEFetcher:
             t0 = time.perf_counter()
             view = self._view()
             if view is not None:
-                slots, present = view.lookup(users)
+                with maybe_span(tr, "bse.lookup"):
+                    slots, present = view.lookup(users)
                 rows = view.rows(slots)
                 self._touch_misses(users, present)
             else:
@@ -314,7 +315,8 @@ class BSEFetcher:
                         return jnp.concatenate(
                             [self.fetch_many(users[lo:hi])
                              for lo, hi in chunks])
-                slots, present = self.store.lookup(users)
+                with maybe_span(tr, "bse.lookup"):
+                    slots, present = self.store.lookup(users)
                 rows = self.store.rows(slots)
             misses = len(users) - int(present.sum())
             if misses:
@@ -352,7 +354,8 @@ class BSEFetcher:
             t0 = time.perf_counter()
             view = self._view()
             if view is not None:
-                slots, present = view.lookup(users)
+                with maybe_span(tr, "bse.lookup"):
+                    slots, present = view.lookup(users)
                 data, scales = view.data, view.scales
                 self._touch_misses(users, present)
             else:
@@ -364,7 +367,8 @@ class BSEFetcher:
                             [self.serve_candidates(users[lo:hi], q[lo:hi],
                                                    R=R)
                              for lo, hi in chunks])
-                slots, present = self.store.lookup(users)
+                with maybe_span(tr, "bse.lookup"):
+                    slots, present = self.store.lookup(users)
                 data, scales = self.store.data, self.store.scales
             if self.store.sharded:
                 out = self.engine.serve_fused_sharded(

@@ -43,6 +43,21 @@ disabled tracer (``enabled=False``) returns the shared ``NOOP_SPAN``
 singleton from ``span()`` — no allocation, no clock read, no lock. The
 disabled-overhead bound is pinned by tests/test_tracing.py.
 
+One clock with the device
+-------------------------
+While a tracer is enabled, every ``span(name)`` also enters a
+``jax.profiler.TraceAnnotation(name)`` and leaves it when the span
+closes (exception unwinds included). Under ``jax.profiler.start_trace``
+each span then appears, under its own name and nested as its parent
+links say, on the host plane of the profiler trace, on the profiler's
+clock beside the device's ops; outside a profiler session an annotation
+is a cheap no-op. Two things stay Tracer-only: ``add_span`` (a finished span handed
+over from another thread, e.g. the async-ingest fold) cannot become an
+annotation after the fact, and the ``ctr.score`` -> ``ctr.jit_compile``
+rename happens after the annotation was named, so a compiling dispatch
+shows as ``ctr.score`` in the profiler trace (the chip benchmark
+requires zero compilations in its measured window).
+
 Export: ``to_chrome_trace()`` renders the retained traces as Chrome
 trace-event JSON (``"X"`` complete events, µs timestamps, one ``tid`` per
 thread name) loadable in Perfetto / ``chrome://tracing``; ``report()``
@@ -57,6 +72,8 @@ import random
 import threading
 import time
 from typing import Any, Callable, NamedTuple, Optional, Sequence
+
+from jax.profiler import TraceAnnotation
 
 
 class SpanContext(NamedTuple):
@@ -143,15 +160,18 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _SpanHandle:
-    """Context manager binding an open ``Span`` to its tracer; closing the
-    ROOT span hands the trace to the retention policy."""
+    """Context manager binding an open ``Span`` (and its profiler
+    annotation) to its tracer; closing the ROOT span hands the trace to
+    the retention policy."""
 
-    __slots__ = ("_tracer", "_trace", "span")
+    __slots__ = ("_tracer", "_trace", "span", "_ann")
 
-    def __init__(self, tracer: "Tracer", trace: Trace, span: Span):
+    def __init__(self, tracer: "Tracer", trace: Trace, span: Span,
+                 ann: TraceAnnotation):
         self._tracer = tracer
         self._trace = trace
         self.span = span
+        self._ann = ann
 
     # attr passthroughs so call sites treat handle and span alike
     def set(self, **attrs) -> None:
@@ -169,7 +189,7 @@ class _SpanHandle:
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer._exit_span(self._trace, self.span)
+        self._tracer._exit_span(self._trace, self.span, self._ann)
 
 
 def maybe_span(tracer: Optional["Tracer"], name: str, **attrs):
@@ -232,11 +252,14 @@ class Tracer:
         if not self.enabled:
             return NOOP_SPAN
         st = self._stack()
+        # the annotation brackets the clock reads, so the two agree
+        ann = TraceAnnotation(name)
+        ann.__enter__()
         t0 = self.clock()
         sid = next(self._ids)
         tname = threading.current_thread().name
         if st:
-            trace, parent = st[-1]
+            trace, parent, _ = st[-1]
             sp = Span(name, sid, parent.span_id, t0, tname)
         else:
             tid = f"t{next(self._ids):08x}"
@@ -250,15 +273,18 @@ class Tracer:
         self.n_spans += 1
         with self._lock:
             trace.spans.append(sp)
-        st.append((trace, sp))
-        return _SpanHandle(self, trace, sp)
+        st.append((trace, sp, ann))
+        return _SpanHandle(self, trace, sp, ann)
 
-    def _exit_span(self, trace: Trace, span: Span) -> None:
-        span.t1 = self.clock()
+    def _exit_span(self, trace: Trace, span: Span,
+                   ann: TraceAnnotation) -> None:
         st = self._stack()
-        # pop through abandoned inner frames (exception unwound past them)
+        # pop through abandoned inner frames (exception unwound past them),
+        # leaving their annotations innermost first
         while st and st[-1][1] is not span:
-            st.pop()
+            st.pop()[2].__exit__(None, None, None)
+        span.t1 = self.clock()
+        ann.__exit__(None, None, None)
         if st:
             st.pop()
         if span.parent_id is None:
@@ -272,7 +298,7 @@ class Tracer:
         st = getattr(self._tls, "stack", None)
         if not st:
             return None
-        trace, span = st[-1]
+        trace, span, _ = st[-1]
         return SpanContext(trace.trace_id, span.span_id)
 
     def annotate(self, **attrs) -> None:

@@ -127,3 +127,42 @@ def test_sharded_store_op_compiles_for_v5e_2x2(topo, op):
             else (store,)
         args = (*head, ids, ids, on((B,), P(), bool), on((B, C, D), P()), r)
     assert "tpu_custom_call" in fn.lower(*args).compile().as_text()
+
+
+def _reader_attr(metric, attr):
+    """A constant of the chip benchmark's reader ``metrics/<metric>.py``."""
+    import importlib.util
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip")
+    sp = importlib.util.spec_from_file_location(
+        "reader_" + metric, os.path.join(bench, "metrics", metric + ".py"))
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return getattr(mod, attr)
+
+
+@pytest.mark.parametrize("metric", ["sdim_query_roofline",
+                                    "sdim_fused_serve_roofline"])
+def test_kernel_names_match_the_roofline_readers(spec, metric):
+    """The roofline readers find each kernel as the custom call named after
+    the engine's jitted function (``_query``, ``_serve_fused_impl``) inside
+    whatever program calls it: renaming the function loses the metric."""
+    import re
+
+    from repro.core import engine
+
+    static = dict(tau=TAU, backend="pallas", block_c=128, interpret=False)
+    if metric == "sdim_query_roofline":
+        fn = lambda q, t, r: 2 * engine._query(q, t, r, **static)
+        args = (spec((B, C, D)), spec((B, G, U, D)), spec((M, D)))
+    else:
+        fn = lambda st, sl, pr, q, sc, r: 2 * engine._serve_fused(
+            st, sl, pr, q, sc, r, **static)
+        args = (spec((N, G, U, D), jnp.int8), spec((B,), jnp.int32),
+                spec((B,), bool), spec((B, C, D)), spec((N, G, U)),
+                spec((M, D)))
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    kernel = re.compile(_reader_attr(metric, "KERNEL"))
+    ops = [line.strip().removeprefix("ROOT ") for line in hlo.splitlines()]
+    assert sum(bool(kernel.search(op)) for op in ops) == 1
