@@ -103,7 +103,9 @@ def build_ctr_server(cfg, *, backend: str = "auto", params=None,
     engine on ``backend``, its params (``params``, else a fresh init from
     key 0) and ``CTRServer.build`` — decoupled BSE + CTR servers for an
     SDIM interest, inline scoring otherwise. ``build_kw`` goes to
-    ``CTRServer.build``. Returns ``(model, params, server)``."""
+    ``CTRServer.build``. Returns ``(model, params, server)``, ``params``
+    being the server's own (embedding tables lane-packed, see
+    ``Embedding.pack``), so a caller holds one copy of each table."""
     from repro.models.ctr import CTRModel
     from repro.serve.ctr_server import CTRServer
 
@@ -114,7 +116,8 @@ def build_ctr_server(cfg, *, backend: str = "auto", params=None,
     if params is None:
         params = model.init(jax.random.PRNGKey(0))
     mode = "decoupled" if cfg.interest.kind == "sdim" else "inline"
-    return model, params, CTRServer.build(model, params, mode, **build_kw)
+    server = CTRServer.build(model, params, mode, **build_kw)
+    return model, server.params, server
 
 
 def synthetic_requests(cfg, n_requests: int, n_candidates: int) -> list:

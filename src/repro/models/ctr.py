@@ -165,12 +165,28 @@ class CTRModel:
         raise ValueError(cfg.arch)
 
     # ---------------- shared featurization ----------------
+    def _tables(self):
+        cfg = self.cfg
+        return {"item_emb": Embedding(cfg.n_items, cfg.embed_dim),
+                "cat_emb": Embedding(cfg.n_cats, cfg.embed_dim)}
+
+    def pack_tables(self, params) -> Params:
+        """``params`` with the item and category tables in the serving
+        layout (``Embedding.pack``); every forward reads either layout."""
+        return {**params, **{name: emb.pack(params[name])
+                             for name, emb in self._tables().items()}}
+
+    def n_packed_tables(self, params) -> int:
+        """How many of the item and category tables ``params`` stores
+        packed."""
+        return sum(emb.rows_per_packed_row(params[name]) > 1
+                   for name, emb in self._tables().items())
+
     def _embed_behaviors(self, params, items, cats):
         # hash trick: raw id spaces fold into the table (industry convention)
-        items = items % self.cfg.n_items
-        cats = cats % self.cfg.n_cats
-        ie = Embedding(self.cfg.n_items, self.cfg.embed_dim).apply(params["item_emb"], items)
-        ce = Embedding(self.cfg.n_cats, self.cfg.embed_dim).apply(params["cat_emb"], cats)
+        tables = self._tables()
+        ie = tables["item_emb"].apply(params["item_emb"], items % self.cfg.n_items)
+        ce = tables["cat_emb"].apply(params["cat_emb"], cats % self.cfg.n_cats)
         return jnp.concatenate([ie, ce], axis=-1)
 
     def _short_slice(self, batch):

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Sequence
 
 import jax
@@ -68,8 +69,29 @@ class RMSNorm:
         return y.astype(x.dtype)
 
 
+LANES = 128  # a TPU vreg's lane count: the minor tile of a row-major array
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _pack_rows(table, k):
+    v, d = table.shape
+    table = jnp.pad(table, ((0, -v % k), (0, 0)))
+    return table.reshape(-1, k * d)
+
+
 @dataclasses.dataclass(frozen=True)
 class Embedding:
+    """A (vocab, dim) lookup table.
+
+    ``pack`` gives the serving layout of a narrow table: with
+    ``k = LANES // dim`` rows per packed row, a (V, dim) table becomes
+    (ceil(V / k), LANES), row ``i`` being lane block ``i % k`` of packed row
+    ``i // k``. On the TPU a 2-D float array narrower than 128 lanes is laid
+    out column-major, so every program that gathers rows from it first
+    copies the whole table to row-major; the packed table is row-major
+    already and is gathered in place. ``apply`` reads either form, bit for
+    bit the same, from the table's width."""
+
     vocab: int
     dim: int
     init_std: float = 0.02
@@ -77,8 +99,36 @@ class Embedding:
     def init(self, key):
         return {"table": self.init_std * jax.random.normal(key, (self.vocab, self.dim))}
 
+    def rows_per_packed_row(self, params) -> int:
+        """``k``: how many rows of the table one stored row holds (1 for a
+        table stored as it was made)."""
+        return params["table"].shape[-1] // self.dim
+
+    def pack(self, params):
+        """The table lane-packed (class docstring); a table that is packed
+        already, or whose ``dim`` is not a proper divisor of ``LANES``, is
+        returned as it is. Pad rows are zero and never gathered: ids lie in
+        ``[0, vocab)``."""
+        k = LANES // self.dim
+        if (self.rows_per_packed_row(params) != 1 or k == 1
+                or LANES % self.dim):
+            return params
+        return {**params, "table": _pack_rows(params["table"], k)}
+
     def apply(self, params, ids):
-        return jnp.take(params["table"], ids, axis=0)
+        table = params["table"]
+        k = self.rows_per_packed_row(params)
+        if k == 1:
+            return jnp.take(table, ids, axis=0)
+        # ids lie in [0, vocab) (``pack``), so the packed row is in range
+        # and the gather needs no out-of-range fill pass
+        rows = table.at[ids // k].get(mode="promise_in_bounds")  # (..., k·dim)
+        lane = (ids % k)[..., None]
+        out = rows[..., :self.dim]
+        for j in range(1, k):
+            out = jnp.where(lane == j,
+                            rows[..., j * self.dim:(j + 1) * self.dim], out)
+        return out
 
     def attend(self, params, x):
         """Tied-embedding logits: x @ table.T"""

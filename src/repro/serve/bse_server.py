@@ -417,6 +417,7 @@ class BSEServer:
         tracer: Optional[Tracer] = None,
         cold_deadline_s: Optional[float] = None,
         clock: Optional[Callable[[], float]] = None,
+        pack_params: Optional[Callable[[Any], Any]] = None,
     ):
         """``mesh`` (a Mesh or MeshCtx) shards the table store over the
         mesh's model axis (``ShardedTableStore``): capacity scales with the
@@ -454,7 +455,11 @@ class BSEServer:
         ``cold_deadline_s`` arms the tiered store's
         cold-tier circuit breaker (degrade-to-miss, see
         serve/tiered_store.py); ``clock`` injects a virtual clock for
-        deterministic fault tests."""
+        deterministic fault tests. ``pack_params`` puts the params of
+        every ``refresh_params`` in the layout ``params`` already has
+        (``CTRModel.pack_tables``)."""
+        self.pack_params = (lambda p: p) if pack_params is None \
+            else pack_params
         self.engine = engine
         self.R = engine.R if R is None else R
         self.wire_dtype = jnp.dtype(wire_dtype)
@@ -537,7 +542,9 @@ class BSEServer:
         """Model push: new embeddings invalidate the whole store (re-encoded
         lazily; the slot index is emptied so no stale slot can be read).
         Async: queued-but-unfolded behaviors are from the OLD model and are
-        dropped with the store; the runtime commits a fresh empty version."""
+        dropped with the store; the runtime commits a fresh empty version.
+        The pushed params go through ``pack_params`` first."""
+        params = self.pack_params(params)
         if self.async_ingest is not None:
             self.async_ingest.refresh(params)
             return

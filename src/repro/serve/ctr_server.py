@@ -137,10 +137,16 @@ class CTRServer:
                 "cold_deadline_s arms the cold-tier circuit breaker, which "
                 "needs the tiered store (pass hot_capacity=/store_dir=/"
                 "policy=/warm_capacity=)")
+        # the BSE server's ingest embeds from the same packed tables, in
+        # one program per shape: op by op, every intermediate of the packed
+        # gather would be a buffer as large as the embeddings
+        params = model.pack_tables(params)
         if mode == "decoupled":
-            embed = lambda p, i, c: model._embed_behaviors(
-                p, jnp.asarray(i), jnp.asarray(c))
-            bse = BSEServer(embed, params, model.engine,
+            def bse_embed(p, items, cats):
+                return model._embed_behaviors(p, items, cats)
+
+            bse = BSEServer(jax.jit(bse_embed), params, model.engine,
+                            pack_params=model.pack_tables,
                             R=params["interest"]["buffers"]["R"],
                             wire_dtype=wire_dtype, capacity=capacity,
                             mesh=mesh, hot_capacity=hot_capacity,
@@ -174,7 +180,10 @@ class CTRServer:
         if mode == "decoupled":
             assert bse_server is not None
         self.model = model
-        self.params = params
+        # narrow embedding tables are stored lane-packed (Embedding.pack):
+        # a row gather then reads the table in place instead of copying
+        # all of it to row-major in every program (idempotent)
+        self.params = model.pack_tables(params)
         self.bse = bse_server
         self.mode = mode
         self.fused = fused
@@ -183,6 +192,9 @@ class CTRServer:
             bse_server.metrics if bse_server is not None else None)
         self.tracer = tracer
         self.stats = ServeStats()
+        if self.metrics is not None:
+            self.metrics.gauge("ctr.packed_tables").set(
+                model.n_packed_tables(self.params))
         # named functions, so each compiled program carries a fixed module
         # name (jit_ctr_score_tables, ...) that the device trace shows
         def ctr_score_tables(p, u, ci, cc, ctx, tb):

@@ -166,3 +166,69 @@ def test_kernel_names_match_the_roofline_readers(spec, metric):
     kernel = re.compile(_reader_attr(metric, "KERNEL"))
     ops = [line.strip().removeprefix("ROOT ") for line in hlo.splitlines()]
     assert sum(bool(kernel.search(op)) for op in ops) == 1
+
+
+# the paper's online model (benchmarks/chip/configs/sdim_paper_*.json)
+N_ITEMS, N_CATS, EMB, SHORT, BURST = 10_000_000, 100_000, 64, 50, 16
+
+
+def _table_sized_results(hlo: str, n: int) -> list:
+    """Instructions of ``hlo`` other than parameters whose result holds
+    ``n`` elements or more."""
+    import math
+    import re
+
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w-]+)\(", line)
+        if m is None or m.group(2) == "parameter":
+            continue
+        dims = re.findall(r"[a-z]+\d*\[([\d,]*)\]", m.group(1))
+        if any(math.prod(int(x) for x in d.split(",") if x) >= n
+               for d in dims):
+            out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("program", ["ctr_score_tables", "ctr_score_interest",
+                                     "ctr_embed_targets"])
+def test_serving_programs_gather_tables_in_place(spec, program):
+    """The serving programs, compiled at the paper's widths with the
+    server's lane-packed tables, hold nothing the size of the item table
+    but the table itself; with the tables as made, the same check finds
+    the row-major copy a 64-wide table needs for every row gather."""
+    from repro.core.interest import InterestConfig
+    from repro.models.ctr import CTRConfig, CTRModel
+    from repro.serve.ctr_server import CTRServer
+
+    cfg = CTRConfig(arch="din", n_items=N_ITEMS, n_cats=N_CATS,
+                    embed_dim=EMB, short_len=SHORT, long_len=L,
+                    mlp_hidden=(1024, 512, 256),
+                    interest=InterestConfig(kind="sdim", m=M, tau=TAU,
+                                            backend="pallas",
+                                            interpret=False))
+    model = CTRModel(cfg)
+    made = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    packed = jax.eval_shape(model.pack_tables, made)
+    server = CTRServer(model, packed, mode="inline")
+    assert model.n_packed_tables(server.params) == 2
+    shaped = lambda tree: jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype), tree)
+    ci = spec((BURST, C), jnp.int32)
+    hist = {"hist_items": spec((BURST, SHORT), jnp.int32),
+            "hist_cats": spec((BURST, SHORT), jnp.int32),
+            "hist_mask": spec((BURST, SHORT))}
+    fn, rest = {
+        "ctr_score_tables": (server._score_many_table,
+                             (hist, ci, ci, spec((BURST, C, 4)),
+                              spec((BURST, G, U, D), jnp.bfloat16))),
+        "ctr_score_interest": (server._score_many_interest,
+                               (hist, ci, ci, spec((BURST, C, 4)),
+                                spec((BURST, C, D)))),
+        "ctr_embed_targets": (server._embed_targets, (ci, ci)),
+    }[program]
+    n = N_ITEMS * EMB
+    hlo = fn.lower(shaped(server.params), *rest).compile().as_text()
+    assert _table_sized_results(hlo, n) == []
+    hlo = fn.lower(shaped(made), *rest).compile().as_text()
+    assert any(" copy(" in op for op in _table_sized_results(hlo, n))
