@@ -40,12 +40,6 @@ def sample(n: int, seed: int, k: int) -> np.ndarray:
     return np.sort(rng.choice(n, size=min(k, n), replace=False))
 
 
-def ref_cfg(cfg: dict) -> tuple:
-    """The configuration as a hashable static argument for the reference."""
-    keys = ("n_items", "n_cats", "tau", "fused")
-    return tuple((k, cfg[k]) for k in keys)
-
-
 def reference_outputs(cfg: dict, w: dict, hist: dict, req: dict, *,
                       dtype, qmax, wire) -> dict:
     """Reference tables of the sampled users and, for the sampled
@@ -54,9 +48,10 @@ def reference_outputs(cfg: dict, w: dict, hist: dict, req: dict, *,
     each request's row in ``hist``."""
     import jax.numpy as jnp
 
-    from chipbench import reference
+    from chipbench import reference, spec
 
-    rc = ref_cfg(cfg)
+    # all of the configuration, so that its tower reads any key
+    rc = spec.frozen(cfg)
     tables = reference.user_tables(
         w, jnp.asarray(hist["items"]), jnp.asarray(hist["cats"]),
         jnp.asarray(hist["mask"]), cfg=rc, dtype=dtype, qmax=qmax)

@@ -1,11 +1,21 @@
 """One run of one cell: set-up, the measured window and the check.
 
 Set-up builds the deployment through the program's own entry points
-(``launch.serve.build_ctr_server`` -> ``CTRServer.build`` with
-``capacity`` set to the population, so the store never grows), ingests
+(``launch.serve.build_ctr_server`` -> ``CTRServer.build``), ingests
 every user's history through ``bse.ingest_histories`` in fixed chunks,
 and warms every burst size from 1 to ``max_burst`` through
-``handle_requests``. The window then drives ``handle_requests`` open-loop
+``handle_requests``.
+
+On one chip the store is built at the population's capacity, so it
+never grows. A cell of several chips passes ``mesh=``, a mesh over its
+first ``chips`` devices (``launch.serve.build_mesh``), so the BSE tables
+live row-sharded in the program's ``ShardedTableStore``. That store
+makes its zeros on one device and slices them there before it shards
+them, so it is built at a sixteenth of the population, and ingest grows
+it, each shard in place (``ShardedTableStore.grow``), by four doublings
+to the whole population; nothing grows in the window.
+
+The window then drives ``handle_requests`` open-loop
 from one thread: whenever the server is free it is handed every request
 that is due, up to ``max_burst``, as one burst. A request's latency runs
 from its due time until its scores are on the host.
@@ -27,6 +37,7 @@ from chipbench import checks, histories, spec, traffic, weights
 CHUNK = 1024          # users per ingest_histories call
 SAMPLE = 64           # requests compared with the reference
 GRACE_S = 60.0        # how long past the window a due request may finish
+SHARDED_START = 16    # a sharded store starts at population / SHARDED_START
 CACHE_DIR = os.path.join(spec.ROOT, ".jax_cache")
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/core/compile/jaxpr_trace_duration")
@@ -46,15 +57,18 @@ def prepare_process() -> None:
 
 
 def model_config(cfg: dict):
+    """The program's ``CTRConfig``: the shared widths, and the keywords of
+    the tower that the configuration's ``arch`` names."""
     from repro.core.interest import InterestConfig
     from repro.models.ctr import CTRConfig
 
     return CTRConfig(
-        arch="din", n_items=cfg["n_items"], n_cats=cfg["n_cats"],
+        arch=cfg["arch"], n_items=cfg["n_items"], n_cats=cfg["n_cats"],
         embed_dim=cfg["embed_dim"], short_len=cfg["short_len"],
         long_len=cfg["long_len"], mlp_hidden=tuple(cfg["mlp_hidden"]),
         ctx_dim=cfg["ctx_dim"],
-        interest=InterestConfig(kind="sdim", m=cfg["m"], tau=cfg["tau"]))
+        interest=InterestConfig(kind="sdim", m=cfg["m"], tau=cfg["tau"]),
+        **spec.tower(cfg["arch"]).config_kwargs(cfg))
 
 
 def history_shape(cfg: dict) -> histories.HistoryShape:
@@ -148,7 +162,7 @@ class Run:
         import jax
         import jax.numpy as jnp
 
-        from repro.launch.serve import build_ctr_server
+        from repro.launch.serve import build_ctr_server, build_mesh
         from repro.serve.tracing import Tracer
 
         cfg, mix = self.cfg, self.mix
@@ -161,9 +175,12 @@ class Run:
                        if self.trace else None)
         self.model, self.params, self.server = build_ctr_server(
             model_config(cfg), backend="auto", params=w,
-            capacity=cfg["population"], fused=cfg["fused"],
+            capacity=cfg["population"] // (
+                1 if self.cell.chips == 1 else SHARDED_START),
+            fused=cfg["fused"],
             table_dtype=jnp.dtype(cfg["table_dtype"]),
-            wire_dtype=jnp.dtype(cfg["wire_dtype"]), tracer=self.tracer)
+            wire_dtype=jnp.dtype(cfg["wire_dtype"]), tracer=self.tracer,
+            mesh=build_mesh(self.cell.chips))
         eng = self.model.engine
         if self.require_chip and (eng.backend != "pallas" or eng.interpret):
             raise RuntimeError(f"engine backend {eng.backend} (interpret "

@@ -112,8 +112,9 @@ def covered(merged: list, lo: int, hi: int) -> int:
 
 def module_time(progs: Programs, red: tracereduce.Reduced,
                 pattern: str) -> tuple:
-    """(seconds, calls) of the device-0 module executions in the window
-    whose name matches ``pattern`` (a regular expression)."""
+    """(seconds, calls) of the module executions of the device
+    ``tracereduce.READ`` in the window whose name matches ``pattern`` (a
+    regular expression)."""
     if not progs.modules:
         return 0.0, 0
     return tracereduce.op_time(tracereduce.Trace(progs.modules, []), red,
@@ -129,7 +130,8 @@ def device_lead(progs: Programs) -> int:
     lead = 0
     for span, pattern in DISPATCHES.items():
         rx = re.compile(pattern)
-        runs = sorted(s for n, s, _ in (progs.modules or [[]])[0]
+        runs = sorted(s for n, s, _ in
+                      (progs.modules or [[]])[tracereduce.READ]
                       if rx.search(n))
         calls = sorted(s for n, s, _ in progs.program if n == span)
         if calls and len(calls) == len(runs):
@@ -138,10 +140,10 @@ def device_lead(progs: Programs) -> int:
 
 
 def device_busy(red: tracereduce.Reduced, progs: Programs) -> list:
-    """Device 0's merged busy intervals, shifted onto the host's clock by
-    ``device_lead``."""
+    """The merged busy intervals of the device ``tracereduce.READ``,
+    shifted onto the host's clock by ``device_lead``."""
     lead = device_lead(progs)
-    return [(s + lead, e + lead) for s, e in red.busy[0]]
+    return [(s + lead, e + lead) for s, e in red.busy[tracereduce.READ]]
 
 
 def innermost(spans: list) -> list:
@@ -193,7 +195,8 @@ def _label(intervals: list, segments: list, totals: dict) -> list:
 
 
 def idle_by_span(red: tracereduce.Reduced, progs: Programs) -> dict:
-    """Seconds in the window in which device 0 ran nothing (its events
+    """Seconds in the window in which the device ``tracereduce.READ`` ran
+    nothing (its events
     shifted by ``device_lead``), by the innermost program span open at the
     time; where none is open, by the benchmark's annotation
     (``bench.handle_requests``, ``bench.wait_arrival``) or ``other``.

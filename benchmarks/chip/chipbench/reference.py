@@ -11,8 +11,8 @@ program. It follows the paper's equations:
   (masked) behaviours whose signature in g is u (Eq. 8/11);
 * a candidate's long-term interest is the mean over groups of the
   l2-normalised bucket it falls in (Eq. 12; an empty bucket reads 0);
-* the DIN short-term interest is softmax(q . s / sqrt(e)) attention over
-  the recent window;
+* the short-term interest over the recent window is the configuration's
+  tower's (``towers/<arch>.py``, found by the ``arch`` key);
 * the head is an MLP with ReLU on [target, short, long, ctx].
 
 What the deployment adds is modelled too, since it is part of what is
@@ -30,6 +30,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 DEFAULT = jax.lax.Precision.DEFAULT
@@ -87,18 +89,6 @@ def interest(q, table, R, tau: int, dtype):
     return out / G
 
 
-def short_interest(q, seq, mask, dtype):
-    """DIN target attention: q (C, e) over the recent window (s, e)."""
-    e = q.shape[-1]
-    s = jnp.einsum("ce,se->cs", q.astype(dtype), seq.astype(dtype),
-                   precision=DEFAULT, preferred_element_type=dtype)
-    s = s.astype(jnp.float32) / jnp.sqrt(jnp.float32(e))
-    s = jnp.where(mask[None, :] > 0, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(dtype)
-    return jnp.einsum("cs,se->ce", p, seq.astype(dtype), precision=DEFAULT,
-                      preferred_element_type=dtype)
-
-
 def head(w: dict, x, dtype):
     n = len(w["head"])
     for i in range(n):
@@ -132,6 +122,7 @@ def serve(w, table, ci, cc, ctx, hi, hc, hm, *, cfg, dtype, wire):
     c = dict(cfg)
     R = w["interest"]["buffers"]["R"]
     wd = jnp.dtype(wire)
+    tower = spec.tower(c["arch"])
 
     def one(tb, ci, cc, ctx, hi, hc, hm):
         q = embed(w, ci, cc, c["n_items"], c["n_cats"], dtype)
@@ -145,7 +136,7 @@ def serve(w, table, ci, cc, ctx, hi, hc, hm, *, cfg, dtype, wire):
             long = interest(q, tb, R, c["tau"], dtype)
             sent = long
         seq = embed(w, hi, hc, c["n_items"], c["n_cats"], dtype)
-        short = short_interest(q, seq, hm, dtype)
+        short = tower.short_interest(w, q, seq, hm, cfg=c, dtype=dtype)
         x = jnp.concatenate([q.astype(dtype), short.astype(dtype),
                              long.astype(dtype), ctx.astype(dtype)], axis=-1)
         return sent.astype(jnp.float32), head(w, x, dtype).astype(jnp.float32)

@@ -9,6 +9,8 @@ file (``configs/*.json``): ``e`` is the behaviour width (2 x embed_dim),
 """
 from __future__ import annotations
 
+from chipbench import spec
+
 
 def widths(cfg: dict) -> dict:
     e = 2 * cfg["embed_dim"]
@@ -44,15 +46,19 @@ def sdim_read_bytes(B: int, C: int, cfg: dict, table_itemsize: int,
     return table + scale + q_out + w["m"] * w["e"] * 4
 
 
+def head_dims(cfg: dict) -> tuple:
+    """Widths of the MLP head's layers, from the input that the
+    configuration's tower (``towers/<arch>.py``) gives it to the score."""
+    return (spec.tower(cfg["arch"]).head_in(cfg), *cfg["mlp_hidden"], 1)
+
+
 def tower_flops(C: int, cfg: dict) -> int:
-    """Per request: DIN short-term target attention of C candidates over
-    the recent window (scores and weighted sum, 2·2·s·e each) and the MLP
-    head on [target, short, long, ctx] (3·e + ctx inputs)."""
-    w = widths(cfg)
-    attn = 2 * 2 * C * w["s"] * w["e"]
-    dims = (3 * w["e"] + w["ctx"], *w["mlp"], 1)
+    """Per request of C candidates: the short-term branch of the
+    configuration's tower (its ``flops``) and the MLP head on
+    [target, short, long, ctx]."""
+    dims = head_dims(cfg)
     mlp = 2 * C * sum(a * b for a, b in zip(dims[:-1], dims[1:]))
-    return attn + mlp
+    return spec.tower(cfg["arch"]).flops(C, cfg) + mlp
 
 
 def request_flops(C: int, cfg: dict) -> int:

@@ -10,6 +10,11 @@ metrics need, in plain lists that a test fixture can hold as JSON:
 
 Host and device events share the profiler's clock. Busy time is the union
 of the intervals in which an operation runs on a device.
+
+Every reading of one device (``idle_share``, ``idle_gaps``, ``device_ops``,
+``op_time``) reads ``READ``: the chip on one chip, the last chip on
+several. On four TPU v5e chips chip 0's ``XLA Ops`` line stops within the
+first second of a trace while the other chips keep every op (PERF.md).
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ import re
 WINDOW = "bench.window"
 BURST = "bench.handle_requests"
 WAIT = "bench.wait_arrival"
+READ = -1            # the device that the readers read
 
 
 @dataclasses.dataclass
@@ -127,17 +133,16 @@ class Reduced:
 
     def idle_share(self) -> float:
         """Share of the time inside ``handle_requests`` calls in which no
-        operation ran, averaged over devices (0 to 1)."""
+        operation ran on the device ``READ`` (0 to 1)."""
         total = length(self.bursts)
-        busy = sum(length(intersect(b, self.bursts)) for b in self.busy)
-        return 1.0 - busy / (total * len(self.busy))
+        return 1.0 - length(intersect(self.busy[READ], self.bursts)) / total
 
     def idle_gaps(self, k: int = 10) -> list:
-        """Idle gaps of device 0 in the window by what the host was doing
+        """Idle gaps of the device ``READ`` in the window by what the host was doing
         (inside a ``handle_requests`` call, waiting for an arrival, or
         neither): the total of each, then the longest gaps, k in all."""
         gaps, t = [], self.window[0]
-        for s, e in self.busy[0] + [(self.window[1], self.window[1])]:
+        for s, e in self.busy[READ] + [(self.window[1], self.window[1])]:
             if s > t:
                 gaps.append((t, s))
             t = max(t, e)
@@ -177,10 +182,10 @@ def reduce(trace: Trace) -> Reduced:
 
 def device_ops(trace: Trace, red: Reduced, k: int = 10) -> list:
     """The k operations that took most device time in the window, summed
-    over their calls, in seconds (device 0)."""
+    over their calls, in seconds (the device ``READ``)."""
     tot = {}
     lo, hi = red.window
-    for name, s, e in trace.ops[0]:
+    for name, s, e in trace.ops[READ]:
         if e > lo and s < hi:
             lab = op_label(name)
             tot[lab] = tot.get(lab, 0.0) + (min(e, hi) - max(s, lo)) * 1e-9
@@ -188,12 +193,12 @@ def device_ops(trace: Trace, red: Reduced, k: int = 10) -> list:
 
 
 def op_time(trace: Trace, red: Reduced, pattern: str) -> tuple:
-    """(seconds, calls) of the device-0 operations in the window whose
-    name matches ``pattern`` (a regular expression)."""
+    """(seconds, calls) of the operations of the device ``READ`` in the
+    window whose name matches ``pattern`` (a regular expression)."""
     rx = re.compile(pattern)
     lo, hi = red.window
     sec, n = 0.0, 0
-    for name, s, e in trace.ops[0]:
+    for name, s, e in trace.ops[READ]:
         if s >= lo and e <= hi and rx.search(name):
             sec += (e - s) * 1e-9
             n += 1

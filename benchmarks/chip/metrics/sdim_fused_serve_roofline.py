@@ -6,9 +6,13 @@ HBM bandwidth (``chipbench/shapes.py``), over the kernel's device time
 in the profiler trace.
 
 The kernel is found as the custom call named after the engine's jitted
-``_serve_fused_impl`` (``repro.core.engine``); one call per burst."""
+``_serve_fused_impl`` (``repro.core.engine``), or on a row-sharded store
+after the ``shard_map`` of its ``_sharded_fused_serve_fn``; one call per
+burst, on the device ``tracereduce.READ``. Each shard's call serves the
+whole burst (foreign users read local row 0 and are masked after the
+kernel), so its least time is that of a one-chip call."""
 
-KERNEL = r"^%_serve_fused_impl(\.\d+)? = .*custom-call\("
+KERNEL = r"^%(_serve_fused_impl|shard_map)(\.\d+)? = .*custom-call\("
 
 
 def read(ctx):

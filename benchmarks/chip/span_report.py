@@ -7,7 +7,7 @@ reports where the device waits in the window, by program span.
 
 Before ``run.py`` removes the profiler trace, it is read once more
 (``chipbench/programtrace.py``) and one line of JSON is printed:
-``idle_by_span`` (device-0 idle seconds by innermost program span, in
+``idle_by_span`` (the read device's idle seconds by innermost program span, in
 all and per burst), ``device_lead_ms`` (how far the profiler put the
 device's clock ahead of the host's) and ``named_share``, the share of
 the idle time inside the ``handle_requests`` calls that a program span

@@ -17,8 +17,8 @@ def full():
         return json.load(f)
 
 
-SMOKE = {"embed_dim": 8, "m": 12, "tau": 2, "short_len": 8, "ctx_dim": 4,
-         "mlp_hidden": [32, 16]}
+SMOKE = {"arch": "din", "embed_dim": 8, "m": 12, "tau": 2, "short_len": 8,
+         "ctx_dim": 4, "mlp_hidden": [32, 16]}
 
 
 def test_full_widths_match_the_paper():

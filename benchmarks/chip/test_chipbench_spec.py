@@ -13,8 +13,6 @@ sys.path.insert(0, HERE)
 from chipbench import peaks, spec  # noqa: E402
 
 BENCH = spec.load_benchmark()
-WIDTHS = ("n_items", "n_cats", "embed_dim", "short_len", "long_len",
-          "mlp_hidden", "ctx_dim", "m", "tau")
 
 
 @pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])
@@ -46,15 +44,19 @@ def test_a_new_metric_is_found_by_its_name(tmp_path):
     assert spec.metric_reader("answer.v2", str(tmp_path))(21) == 42
 
 
-def test_configs_keep_the_paper_widths():
-    paper = {"n_items": 10_000_000, "n_cats": 100_000, "embed_dim": 64,
-             "short_len": 50, "long_len": 1024, "mlp_hidden": [1024, 512, 256],
-             "ctx_dim": 4, "m": 48, "tau": 3}
-    for c in BENCH["configs"]:
-        with open(os.path.join(spec.ROOT, c["file"])) as f:
-            cfg = json.load(f)
-        assert {k: cfg[k] for k in WIDTHS} == paper
-        assert c["reduced"] == cfg["reduced"] == ["population"]
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_configs_keep_the_paper_widths(name):
+    entry = {c["name"]: c for c in BENCH["configs"]}[name]
+    with open(os.path.join(spec.ROOT, entry["file"])) as f:
+        cfg = json.load(f)
+    # the widths that the source publishes for the configuration's tower
+    tower = spec.tower(cfg["arch"])
+    paper = tower.PUBLISHED[entry["source"]]
+    assert {k: cfg[k] for k in paper} == paper
+    assert entry["reduced"] == cfg["reduced"]
+    # only the tower's scale keys may be cut, and none of them is a width
+    assert set(cfg["reduced"]) <= set(tower.SCALE)
+    assert not set(tower.SCALE) & set(paper)
 
 
 def test_unlisted_device_has_no_peaks():

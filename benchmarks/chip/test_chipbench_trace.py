@@ -96,6 +96,7 @@ def reader_context(trace, B):
     bursts = [(s, e, B, 128) for n, s, e in trace.host if n == tr.BURST]
     return types.SimpleNamespace(
         trace=trace, reduced=red, tracereduce=tr, shapes=shapes, cfg=cfg,
+        cell=types.SimpleNamespace(chips=1),
         peaks=peaks.peaks_for("TPU v5 lite"), bursts=bursts, spans=[])
 
 
@@ -148,3 +149,47 @@ def test_end_to_end_latency_covers_every_served_request():
     assert set(got) == {m["name"] for m in run.cell.end_to_end}
     assert got["p50_ms"]["value"] == pytest.approx(50.5)
     assert got["setup_s"] == {"value": 12.5, "unit": "s"}
+
+
+# Two chips of a sharded cell, one burst 10_000..60_000: each runs the
+# sharded kernel (the custom call named after its shard_map) and the psum;
+# chip 0 then runs the candidate embedding, chip 1 the scorer. As on four
+# TPU v5e chips, chip 0's op line stops early: its psum is missing.
+SHARDED = tr.Trace(
+    ops=[[("%shard_map.43 = f32[16,128,128]{2,1,0} custom-call(a, b)",
+           20_000, 40_000),
+          ("%fusion.3 = f32[16]{0} fusion(x)", 44_000, 50_000)],
+         [("%shard_map.43 = f32[16,128,128]{2,1,0} custom-call(a, b)",
+           20_000, 40_000),
+          ("%psum.7 = f32[16,128,128]{2,1,0} all-reduce(%fusion.2)",
+           40_000, 41_000),
+          ("%fusion.9 = f32[16]{0} fusion(x)", 50_000, 55_000)]],
+    host=[("bench.window", 0, 100_000),
+          ("bench.handle_requests", 10_000, 60_000)])
+
+
+def test_a_cell_of_several_chips_is_read_on_its_last_chip():
+    from chipbench import spec
+
+    ctx = reader_context(SHARDED, B=16)
+    ctx.cell.chips = 2
+    ctx.cfg = dict(ctx.cfg, fused=True)
+    red = ctx.reduced
+    # busy_s averages the chips: (26 + 26) us over 2
+    assert red.busy_s == pytest.approx(26e-6)
+    # chip 1 is busy 26 of the burst's 50 us
+    assert spec.metric_reader("idle_share")(ctx) == pytest.approx(48.0)
+    assert spec.metric_reader("allreduce_device_ms")(ctx) == pytest.approx(
+        1e-3)
+    # the shard's kernel serves the whole burst: a one-chip call's least
+    # time over its 20 us
+    ideal = ctx.shapes.least_time(16, 128, ctx.cfg, ctx.peaks,
+                                  table_itemsize=4)
+    assert spec.metric_reader("sdim_fused_serve_roofline")(
+        ctx) == pytest.approx(100.0 * ideal / 20e-6)
+    assert [n for n, _ in tr.device_ops(SHARDED, red)] == [
+        "shard_map.43 f32[16,128,128]", "fusion.9 f32[16]",
+        "psum.7 f32[16,128,128]"]
+    one = spec.metric_reader("mfu")(ctx)
+    ctx.cell.chips = 1
+    assert spec.metric_reader("mfu")(ctx) == pytest.approx(2 * one)
